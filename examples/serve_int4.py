@@ -18,7 +18,8 @@ The generation API (DESIGN.md §10) on display here:
 * a stop-token request that releases its slot early.
 
 Pass backend="pallas" to route matmuls through the int4/int8 Pallas kernels
-(fused dequant+bias+GELU decode epilogue; interpret mode off-TPU).
+(fused dequant+bias+GELU decode epilogue; Mosaic on TPU, interpret mode on
+CPU).
 
 Run:  PYTHONPATH=src python examples/serve_int4.py [--quick]
 """

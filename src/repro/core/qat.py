@@ -14,6 +14,7 @@ int inference path (and its Pallas kernels) can run.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -35,6 +36,13 @@ def _is_linear(node) -> bool:
     return isinstance(node, dict) and "w" in node and "s_w" in node
 
 
+@jax.jit
+def _absmax_k(w):
+    """Per-out-channel |w| max over the K axis (second-to-last), fused so
+    no full-size |w| temporary is made."""
+    return jnp.max(jnp.abs(w), axis=-2, keepdims=True)
+
+
 def calibrate_weight_scales(params, bits_for_leaf: Callable[[tuple], np.ndarray]):
     """Set every linear's s_w = absmax_per_outchannel / l_max(bits).
 
@@ -45,8 +53,7 @@ def calibrate_weight_scales(params, bits_for_leaf: Callable[[tuple], np.ndarray]
         if _is_linear(node):
             w = node["w"]
             s_w = node["s_w"]
-            red = tuple(range(w.ndim))[-2:-1]  # K axis (second-to-last)
-            absmax = jnp.max(jnp.abs(w), axis=red[0], keepdims=True)
+            absmax = _absmax_k(w)
             bits = np.asarray(bits_for_leaf(w.shape[:-2]), np.float32)
             # qrange-consistent l_max: 2^{k-1} for k<8, 127 for the int8 carrier
             qmax = jnp.asarray(np.where(bits >= 8, 2.0 ** (bits - 1) - 1,
@@ -189,17 +196,30 @@ def calibrate_act_scales_global(params, cfg, policy, forward_fn, batches,
 
 # ---------------------------------------------------------------- deployment
 
-def _quantize_stack(tree, w_bits: int):
-    """Replace every linear's 'w' with packed codes 'wq' (segment-sliced)."""
+@functools.partial(jax.jit, static_argnames=("bits", "lo", "hi"))
+def _pack_layers(w, s_w, *, bits: int, lo, hi):
+    """Codes of layers [lo:hi) of one weight stack, sliced and quantized in
+    one fused program: the f32 slice and the quantizer's f32 temporaries
+    are never materialized, so a model whose fp weights fill most of one
+    chip still deploys there."""
+    return quantize_weight(w[lo:hi], s_w[lo:hi], bits)[0]
+
+
+def _quantize_stack(tree, w_bits: int, lo=None, hi=None):
+    """Layers [lo:hi) of a stacked tree (all of an unstacked one, the
+    default); with ``w_bits`` every linear's fp 'w' becomes its packed
+    codes 'wq'."""
+    take = (lambda a: a) if lo is None and hi is None else (lambda a: a[lo:hi])
+
     def walk(node):
-        if _is_linear(node):
-            new = {k: v for k, v in node.items() if k != "w"}
-            wq, _ = quantize_weight(node["w"], node["s_w"], w_bits)
-            new["wq"] = wq
+        if w_bits and _is_linear(node):
+            new = {k: take(v) for k, v in node.items() if k != "w"}
+            new["wq"] = _pack_layers(node["w"], node["s_w"], bits=w_bits,
+                                     lo=lo, hi=hi)
             return new
         if isinstance(node, dict):
             return {k: walk(v) for k, v in node.items()}
-        return node
+        return take(node)
     return walk(tree)
 
 
@@ -213,39 +233,22 @@ def deploy_params(params, cfg: ModelConfig, segments) -> dict:
     xlstm/hybrid: group stacks quantized per segment similarly; shared block
     (hybrid) quantized at the last segment's bits.
     """
+    bits = lambda spec: spec.w_bits if spec.enabled else 0
+    stacks = lambda tree: [_quantize_stack(tree, bits(spec), s, e)
+                           for (s, e, spec) in segments]
     out = dict(params)
     if cfg.family in ("xlstm", "hybrid"):
         key = "mlstm" if cfg.family == "xlstm" else "mamba"
-        stacks = []
-        for (s, e, spec) in segments:
-            seg = jax.tree.map(lambda a: a[s:e], params[key])
-            stacks.append(_quantize_stack(seg, spec.w_bits)
-                          if spec.enabled else seg)
-        out[key] = stacks
+        out[key] = stacks(params[key])
         if cfg.family == "xlstm":
-            out["slstm"] = [
-                _quantize_stack(jax.tree.map(lambda a: a[s:e], params["slstm"]),
-                                spec.w_bits) if spec.enabled else
-                jax.tree.map(lambda a: a[s:e], params["slstm"])
-                for (s, e, spec) in segments]
+            out["slstm"] = stacks(params["slstm"])
         else:
-            last_spec = segments[-1][2]
-            out["shared"] = (_quantize_stack(params["shared"], last_spec.w_bits)
-                             if last_spec.enabled else params["shared"])
+            out["shared"] = _quantize_stack(params["shared"],
+                                            bits(segments[-1][2]))
         return out
     if cfg.family == "encdec":
-        enc_spec = segments[0][2]
-        out["enc"] = (_quantize_stack(params["enc"], enc_spec.w_bits)
-                      if enc_spec.enabled else params["enc"])
-        out["dec"] = [
-            _quantize_stack(jax.tree.map(lambda a: a[s:e], params["dec"]),
-                            spec.w_bits) if spec.enabled else
-            jax.tree.map(lambda a: a[s:e], params["dec"])
-            for (s, e, spec) in segments]
+        out["enc"] = _quantize_stack(params["enc"], bits(segments[0][2]))
+        out["dec"] = stacks(params["dec"])
         return out
-    out["layers"] = [
-        _quantize_stack(jax.tree.map(lambda a: a[s:e], params["layers"]),
-                        spec.w_bits) if spec.enabled else
-        jax.tree.map(lambda a: a[s:e], params["layers"])
-        for (s, e, spec) in segments]
+    out["layers"] = stacks(params["layers"])
     return out

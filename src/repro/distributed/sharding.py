@@ -248,4 +248,8 @@ def shardings_for(tree, mesh: Mesh, specs=None):
 
 
 def make_mesh(shape: tuple, axes: tuple) -> Mesh:
-    return jax.make_mesh(shape, axes)
+    """A device mesh with ``Auto`` axes: every sharding rule here is a
+    GSPMD hint (``with_sharding_constraint``), which ``jax.make_mesh``'s
+    default ``Explicit`` axes (JAX >= 0.7) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

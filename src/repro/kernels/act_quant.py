@@ -47,5 +47,6 @@ def act_quant_pallas(x: jax.Array, s: jax.Array, *, bits: int = 8,
         out_specs=pl.BlockSpec((bm, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, K), jnp.int8),
         interpret=interpret,
+        name="act_quant",
     )(x, s.reshape(1, 1))
     return out[:M] if Mp != M else out

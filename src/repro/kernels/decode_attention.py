@@ -8,10 +8,14 @@ S_max * Hkv * hd K/V floats per layer — drops 4-8x: the kernel DMAs the
 blocks in VMEM inside the online-softmax loop. The fp32 (B, S) score matrix
 never exists in HBM either.
 
-Layout: grid (B, Hkv); each program owns one (slot, kv-head) pair and the
-``group`` query heads mapped to it (GQA). The loop walks the cache in
-``bs``-row blocks carrying (acc, m, l); rows at positions >= the slot's
-cursor are masked (per-slot lengths — serving refills slots independently).
+Layout: grid (B,); each program owns one slot and ALL its Hkv kv-heads, so
+every K/V/scale block spans the full (Hkv, dhp) / (S, Hkv) trailing dims — the
+only blocking of the (B, S, Hkv, dhp) cache layout Mosaic accepts (a 1-wide
+head block in the second-minor dim is refused). Heads are a static loop
+inside the program; each serves its ``group`` query heads (GQA). The loop
+walks the cache in ``bs``-row blocks carrying (acc, m, l); rows at positions
+>= the slot's cursor are masked (per-slot lengths — serving refills slots
+independently).
 The current token's K/V arrive unquantized and are folded in after the loop:
 the new token attends itself at full precision, and the cache write
 (quantize-on-append, models/transformer.write_new_kv) decides what future
@@ -26,33 +30,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kv_pack import unpack_nibbles_last
+from .kv_pack import nibbles_i32
 
 NEG_INF = -2.0e38
 DEFAULT_BS = 128
 
 
+def _interleave_lanes(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """(bs, d/2) x2 int32 codes -> (bs, d) f32 with lo at even lanes.
+
+    The same values as ``kv_pack.unpack_nibbles_last``, but the lane
+    interleave is two matmuls against 0/1 selection matrices: every output
+    is one code times 1 plus zeros, exact at any matmul precision, and
+    Mosaic compiles it in seconds where a stack+reshape lane interleave
+    unrolled over 32 heads takes minutes."""
+    half = lo.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+    even = (c == 2 * r).astype(jnp.float32)
+    odd = (c == 2 * r + 1).astype(jnp.float32)
+    return (jnp.dot(lo.astype(jnp.float32), even)
+            + jnp.dot(hi.astype(jnp.float32), odd))
+
+
 def _dequant_rows(codes: jax.Array, scales: jax.Array) -> jax.Array:
     """(bs, dhp) codes + (bs,) scales -> (bs, dh) f32 rows in VMEM."""
     if codes.dtype == jnp.uint8:
-        codes = unpack_nibbles_last(codes)
+        codes = _interleave_lanes(*nibbles_i32(codes))
     return codes.astype(jnp.float32) * scales[:, None]
 
 
 def _kernel(q_ref, kq_ref, vq_ref, ks_ref, vs_ref, kn_ref, vn_ref, len_ref,
             o_ref, *, bs: int, scale: float):
-    S = kq_ref.shape[1]
+    S, Hkv = kq_ref.shape[1], kq_ref.shape[2]
     n_blk = S // bs
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, dh)
+    ln = len_ref[pl.program_id(0)]
+    for h in range(Hkv):
+        o_ref[0, h] = _attend_head(q_ref, kq_ref, vq_ref, ks_ref, vs_ref,
+                                   kn_ref, vn_ref, h, ln, n_blk, bs=bs,
+                                   scale=scale).astype(o_ref.dtype)
+
+
+def _attend_head(q_ref, kq_ref, vq_ref, ks_ref, vs_ref, kn_ref, vn_ref,
+                 h: int, ln, n_blk: int, *, bs: int, scale: float):
+    """Online-softmax attention of kv-head ``h``'s query group -> (G, dh)."""
+    q = q_ref[0, h].astype(jnp.float32) * scale          # (G, dh)
     G, dh = q.shape
-    ln = len_ref[0, 0]
 
     def body(j, carry):
         acc, m, l = carry
-        k = _dequant_rows(kq_ref[0, pl.ds(j * bs, bs), 0, :],
-                          ks_ref[0, pl.ds(j * bs, bs), 0])       # (bs, dh)
-        v = _dequant_rows(vq_ref[0, pl.ds(j * bs, bs), 0, :],
-                          vs_ref[0, pl.ds(j * bs, bs), 0])
+        k = _dequant_rows(kq_ref[0, pl.ds(j * bs, bs), h, :],
+                          ks_ref[0, pl.ds(j * bs, bs), h])       # (bs, dh)
+        v = _dequant_rows(vq_ref[0, pl.ds(j * bs, bs), h, :],
+                          vs_ref[0, pl.ds(j * bs, bs), h])
         s = q @ k.T                                              # (G, bs)
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
         s = jnp.where(pos < ln, s, NEG_INF)
@@ -69,15 +99,15 @@ def _kernel(q_ref, kq_ref, vq_ref, ks_ref, vs_ref, kn_ref, vn_ref, len_ref,
     acc, m, l = jax.lax.fori_loop(0, n_blk, body, (acc, m, l))
 
     # fold in the current token (fp K/V; it always attends itself)
-    kn = kn_ref[0, 0].astype(jnp.float32)                # (dh,)
-    vn = vn_ref[0, 0].astype(jnp.float32)
+    kn = kn_ref[0, h].astype(jnp.float32)                # (dh,)
+    vn = vn_ref[0, h].astype(jnp.float32)
     s_n = q @ kn                                         # (G,)
     m_new = jnp.maximum(m, s_n)
     p_n = jnp.exp(s_n - m_new)
     corr = jnp.exp(m - m_new)
     l = l * corr + p_n
     acc = acc * corr[:, None] + p_n[:, None] * vn[None, :]
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    return acc / jnp.maximum(l, 1e-30)[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
@@ -97,25 +127,25 @@ def decode_attention_pallas(q: jax.Array, k_q: jax.Array, v_q: jax.Array,
     assert S % bs == 0, (S, bs)
     scale = 1.0 / float(dh) ** 0.5
     qg = q.reshape(B, Hkv, group, dh)
-    lens = lengths.astype(jnp.int32).reshape(B, 1)
+    lens = lengths.astype(jnp.int32).reshape(B)
 
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, scale=scale),
-        grid=(B, Hkv),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, group, dh), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, S, 1, k_q.shape[-1]), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((1, S, 1, v_q.shape[-1]), lambda b, h: (b, 0, h, 0)),
-            pl.BlockSpec((1, S, 1), lambda b, h: (b, 0, h)),
-            pl.BlockSpec((1, S, 1), lambda b, h: (b, 0, h)),
-            pl.BlockSpec((1, 1, dh), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((1, 1, dh), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h: (b, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, Hkv, group, dh), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec((1, S, Hkv, k_q.shape[-1]), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec((1, S, Hkv, v_q.shape[-1]), lambda b: (b, 0, 0, 0)),
+            pl.BlockSpec((1, S, Hkv), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, S, Hkv), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, Hkv, dh), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, Hkv, dh), lambda b: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # all B cursors
         ],
-        out_specs=pl.BlockSpec((1, 1, group, dh), lambda b, h: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, group, dh), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, dh), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(qg, k_q, v_q, k_scale, v_scale, k_new, v_new, lens)
     return out.reshape(B, H, dh)
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .int8_matmul import INT_DOT
 from .kv_pack import INT4_BIAS, unpack_nibbles_rows as _unpack_nibbles
 
 __all__ = ["INT4_BIAS", "int4_matmul_pallas", "int4_matmul_fused_pallas"]
@@ -51,7 +52,7 @@ def _kernel(x_ref, wp_ref, sa_ref, sw_ref, out_ref, acc_ref, *, n_k: int):
     w8 = _unpack_nibbles(wp_ref[...])
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w8, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+        precision=INT_DOT, preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
@@ -75,7 +76,7 @@ def _fused_kernel(x_ref, wp_ref, sa_ref, sw_ref, b_ref, out_ref, acc_ref, *,
     w8 = _unpack_nibbles(wp_ref[...])
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w8, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+        precision=INT_DOT, preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
@@ -115,6 +116,7 @@ def int4_matmul_pallas(x8: jax.Array, wp: jax.Array, s_a: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int4_matmul",
     )(x8, wp, s_a.reshape(1, 1), s_w)
 
 
@@ -154,4 +156,5 @@ def int4_matmul_fused_pallas(x8: jax.Array, wp: jax.Array, s_a: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int4_matmul_fused",
     )(x8, wp, s_a.reshape(1, 1), s_w, bias)

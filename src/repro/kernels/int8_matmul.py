@@ -22,6 +22,10 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 DEFAULT_BK = 512
+# int8 x int8 -> int32 is exact at any precision, but Mosaic refuses the
+# fp32 contraction a caller's jax.default_matmul_precision('highest') asks
+# for: pin the integer dots to the default
+INT_DOT = jax.lax.Precision.DEFAULT
 
 
 def _kernel(x_ref, w_ref, sa_ref, sw_ref, out_ref, acc_ref, *, n_k: int):
@@ -33,7 +37,7 @@ def _kernel(x_ref, w_ref, sa_ref, sw_ref, out_ref, acc_ref, *, n_k: int):
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+        precision=INT_DOT, preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
@@ -69,4 +73,5 @@ def int8_matmul_pallas(x8: jax.Array, w8: jax.Array, s_a: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul",
     )(x8, w8, s_a.reshape(1, 1), s_w)

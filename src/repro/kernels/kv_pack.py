@@ -35,16 +35,24 @@ def kv_qmax(bits: int) -> int:
     raise ValueError(f"kv_bits must be 4 or 8, got {bits}")
 
 
+def nibbles_i32(packed: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Low and high nibbles of packed uint8 bytes as int32 codes in [-7, 8].
+
+    The arithmetic runs in int32 because Mosaic has no int8 vector subtract;
+    callers interleave the two and cast once, at the end."""
+    w = packed.astype(jnp.int32)
+    return (w & 0xF) - INT4_BIAS, (w >> 4) - INT4_BIAS
+
+
 def unpack_nibbles_rows(wp: jax.Array) -> jax.Array:
     """(K/2, N) uint8 -> (K, N) int8 in [-7, 8]; row 2i from the low nibble.
 
     The int4 weight-matmul kernels unpack their HBM slabs with this (packing
     along the contracting axis = rows of the weight block).
     """
-    lo = (wp & 0xF).astype(jnp.int8) - INT4_BIAS
-    hi = (wp >> 4).astype(jnp.int8) - INT4_BIAS
+    lo, hi = nibbles_i32(wp)
     kk, n = wp.shape
-    return jnp.stack([lo, hi], axis=1).reshape(kk * 2, n)
+    return jnp.stack([lo, hi], axis=1).reshape(kk * 2, n).astype(jnp.int8)
 
 
 def pack_nibbles_last(codes: jax.Array) -> jax.Array:
@@ -60,10 +68,10 @@ def pack_nibbles_last(codes: jax.Array) -> jax.Array:
 
 def unpack_nibbles_last(packed: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_nibbles_last`: (..., d/2) uint8 -> (..., d) int8."""
-    lo = (packed & 0xF).astype(jnp.int8) - INT4_BIAS
-    hi = (packed >> 4).astype(jnp.int8) - INT4_BIAS
+    lo, hi = nibbles_i32(packed)
     stacked = jnp.stack([lo, hi], axis=-1)          # (..., d/2, 2)
-    return stacked.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
+    return stacked.reshape(*packed.shape[:-1],
+                           packed.shape[-1] * 2).astype(jnp.int8)
 
 
 def quantize_kv(x: jax.Array, bits: int) -> tuple[jax.Array, jax.Array]:

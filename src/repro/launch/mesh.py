@@ -18,6 +18,8 @@ import dataclasses
 import jax
 import numpy as np
 
+from ..distributed.sharding import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshLayout:
@@ -42,7 +44,7 @@ class MeshLayout:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for_devices(n_devices: int, model_parallel: int = 0, *,
@@ -71,8 +73,8 @@ def make_mesh_for_devices(n_devices: int, model_parallel: int = 0, *,
                 f"allow_degrade=True to halve to the nearest one")
         while n_devices % model_parallel:
             model_parallel //= 2
-    mesh = jax.make_mesh((n_devices // model_parallel, model_parallel),
-                         ("data", "model"))
+    mesh = make_mesh((n_devices // model_parallel, model_parallel),
+                     ("data", "model"))
     return MeshLayout(mesh=mesh, data=n_devices // model_parallel,
                       model=model_parallel, requested_model=requested,
                       degraded=requested > 0 and model_parallel != requested)

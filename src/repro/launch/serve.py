@@ -55,16 +55,18 @@ from ..serving import (EncodeRequest, GenerationRequest,  # noqa: F401
 
 def _build_encoder_model(args):
     """In-process int4 W4A4 BERT classifier artifact for --mode encoder:
-    the paper's deployment target, calibrated on a small synthetic batch."""
+    the paper's deployment target (``--arch`` tinybert4 or bert-base),
+    calibrated on a small synthetic batch."""
     import jax
 
+    from ..configs import get_config, reduced
     from ..core.policy import QuantPolicy
     from ..deploy import ExecutionPlan, deploy
-    from ..models.bert import init_bert_classifier, tinybert_config
+    from ..models.bert import init_bert_classifier
 
-    cfg = (tinybert_config(layers=4, d=96, heads=4, d_ff=192, vocab=512,
-                           name="tinybert4-reduced")
-           if args.reduced else tinybert_config())
+    cfg = get_config(args.arch or "tinybert4")
+    if args.reduced:
+        cfg = reduced(cfg)
     n_units = cfg.num_layers
     k4 = args.int4_last_k if args.int4_last_k >= 0 else n_units
     policy = QuantPolicy(num_layers=n_units, mode="int", last_k_int4=k4)
@@ -90,7 +92,7 @@ def _build_model(args):
     from ..deploy import ExecutionPlan, deploy
     from ..models import api
 
-    cfg = get_config(args.arch)
+    cfg = get_config(args.arch or "stablelm-3b")
     if args.reduced:
         cfg = reduced(cfg)
     n_units = cfg.dec_layers if cfg.family == "encdec" else cfg.num_layers
@@ -118,7 +120,10 @@ def main(argv=None):
     from ..deploy import DeployedModel
 
     p = argparse.ArgumentParser()
-    p.add_argument("--arch", default="stablelm-3b")
+    p.add_argument("--arch", default=None,
+                   help="model from the config registry; default "
+                        "stablelm-3b, or tinybert4 with --mode encoder "
+                        "(which takes tinybert4 or bert-base)")
     p.add_argument("--reduced", action="store_true")
     p.add_argument("--mode", default="decode",
                    choices=["decode", "encoder"],
@@ -146,8 +151,8 @@ def main(argv=None):
     p.add_argument("--backend", default="reference",
                    choices=["reference", "pallas"],
                    help="'pallas' routes matmuls through the int4/int8 "
-                        "Pallas kernels (fused decode epilogue; interpret "
-                        "mode off-TPU)")
+                        "Pallas kernels (fused decode epilogue): Mosaic on "
+                        "TPU, interpret mode on CPU, refused elsewhere")
     p.add_argument("--kv-bits", type=int, default=16, choices=[16, 8, 4],
                    help="serving KV-cache precision (DESIGN.md §8): 16 keeps "
                         "fp rows; 8/4 store packed codes + per-(token, head) "
@@ -427,4 +432,6 @@ def _main_tenants(args):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -241,3 +241,20 @@ def test_decoder_score_is_batch_independent_loglikelihood():
         assert np.isfinite(got) and got <= 0.0    # it is a log-probability
         alone, = run([p])
         np.testing.assert_array_equal(got, alone)
+
+
+@pytest.mark.parametrize("arch", ["tinybert4", "bert-base"])
+def test_serve_encoder_mode_takes_arch(arch, tmp_path, capsys):
+    """``serve --mode encoder --arch`` builds that registry model (reduced
+    here), deploys it W4A4 and serves an encode burst."""
+    from repro.deploy import DeployedModel
+    from repro.launch import serve
+    args = ["--mode", "encoder", "--reduced", "--requests", "2",
+            "--slots", "2", "--act-bits", "4", "--export", str(tmp_path)]
+    if arch != "tinybert4":       # tinybert4 is the encoder default
+        args += ["--arch", arch]
+    serve.main(args)
+    assert "encoder burst: 2 requests (2 done)" in capsys.readouterr().out
+    plan = DeployedModel.load(str(tmp_path)).plan
+    assert plan.cfg.name == arch and plan.mode == "encoder"
+    assert plan.cfg == reduced(get_config(arch))

@@ -48,6 +48,50 @@ def test_int4_matmul_sweep(m, k, n):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("dim,target,align,want", [
+    (100, 128, 8, (100, 100)),      # fits: the whole axis is one block
+    (200, 128, 8, (40, 200)),       # aligned divisor, no padding
+    (6912, 512, 128, (384, 6912)),  # stablelm-3b d_ff as K
+    (1200, 128, 128, (128, 1280)),  # tinybert4 d_ff as N: no divisor, pad
+    (1200, 512, 128, (256, 1280)),
+    (203, 128, 8, (104, 208))])
+def test_tile_choice(dim, target, align, want):
+    block, padded = ops._tile(dim, target, align)
+    assert (block, padded) == want
+    assert padded % block == 0 and (block == dim or block % align == 0)
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("m,k,n", [(200, 1200, 312), (13, 6912, 40),
+                                   (136, 600, 1200)])
+def test_padded_tiles_bit_identical(m, k, n, bits):
+    """Shapes whose tiles need zero-code padding give exactly the unpadded
+    integer product."""
+    x, w = _mk(m, k, n, seed=m + k + n)
+    s_w = weight_scale(w, bits, axis=1)
+    s_a = jnp.float32(float(jnp.max(jnp.abs(x))) / (8 if bits == 4 else 127))
+    xq = ref.act_quant_ref(x, s_a, bits)
+    if bits == 4:
+        wp, _ = quantize_weight(w, s_w, 4)
+        out = ops.int4_matmul(x, wp, s_a, s_w, a_bits=4)
+        exp = ref.int4_matmul_ref(xq, wp, s_a, s_w)
+    else:
+        w8 = jnp.round(jnp.clip(w / s_w, -127, 127)).astype(jnp.int8)
+        out = ops.int8_matmul(x, w8, s_a, s_w)
+        exp = ref.int8_matmul_ref(xq, w8, s_a, s_w)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="interpreted on CPU only"):
+        ops._interpret()
+
+
 @pytest.mark.parametrize("bits", [4, 8])
 @pytest.mark.parametrize("m,k", [(8, 16), (64, 128), (256, 96)])
 def test_act_quant_sweep(m, k, bits):

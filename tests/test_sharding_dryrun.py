@@ -67,7 +67,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
 from repro.launch import dryrun
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh_for_devices
+mesh = make_mesh_for_devices(8, 4).mesh
 results = {}
 for arch, shape in [("stablelm-3b", "train_4k"), ("stablelm-3b", "decode_32k"),
                     ("granite-moe-3b-a800m", "train_4k")]:

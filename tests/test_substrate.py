@@ -187,3 +187,27 @@ def test_elastic_rebalance():
     from repro.launch.elastic import rebalance_batch
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     assert rebalance_batch(256, mesh) == 256
+
+
+# --------------------------------------------------------------- compile cache
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(env_dir, tmp_path, monkeypatch):
+    """A set JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the cache
+    goes to the fixed in-checkout path, the same on every call."""
+    from repro.launch import compile_cache as cc
+    old = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv(cc.ENV, str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv(cc.ENV, raising=False)
+    try:
+        got = cc.enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == old  # untouched
+        else:
+            assert got == str(cc.DEFAULT_DIR) == cc.enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+            assert (cc.DEFAULT_DIR.parent / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
