@@ -462,19 +462,20 @@ class ServingEngine:
         """The public pump: one admit → prefill → batched-decode round.
         Returns the ``(rid, token)`` pairs emitted this step (streams and
         callbacks are fed from inside)."""
-        self._events = []
-        self.last_step_tokens = 0
-        self.last_step_encode_tokens = 0
-        if self.mode == "encoder":
-            self._encoder_step()
-        elif self.prefill_mode == "chunked":
-            self._chunked_step()
-        else:
-            self._token_step()
-        for req in self.scheduler.pop_shed():
-            self._finalize_unslotted(req, "shed")
-        if self.paged:
-            self.metrics.update_kv(self.pool.stats())
+        with self.metrics.span("serve/step"):
+            self._events = []
+            self.last_step_tokens = 0
+            self.last_step_encode_tokens = 0
+            if self.mode == "encoder":
+                self._encoder_step()
+            elif self.prefill_mode == "chunked":
+                self._chunked_step()
+            else:
+                self._token_step()
+            for req in self.scheduler.pop_shed():
+                self._finalize_unslotted(req, "shed")
+            if self.paged:
+                self.metrics.update_kv(self.pool.stats())
         return self._events
 
     # ------------------------------------------------------------ lifecycle
@@ -484,18 +485,19 @@ class ServingEngine:
         metric. Clears the slot's stale token tally up front, so a cancel
         landing between admission and prefill cannot report the previous
         occupant's tokens."""
-        placed = self.scheduler.admit(fits=fits)
-        for s, req in placed:
-            self.generated[s] = []
-            sp = getattr(req, "sampling", None)  # EncodeRequests don't sample
-            if sp is not None:
-                self._seed[s] = np.int32(sp.seed & 0x7FFFFFFF)
-                self._temp[s] = sp.temperature
-                self._topk[s] = sp.top_k
-                self._topp[s] = sp.top_p
-            if req.queue_wait_s is not None:
-                self.metrics.record_wait("queue_wait", req.queue_wait_s,
-                                         tenant=self.tenant)
+        with self.metrics.span("serve/admit"):
+            placed = self.scheduler.admit(fits=fits)
+            for s, req in placed:
+                self.generated[s] = []
+                sp = getattr(req, "sampling", None)  # EncodeRequests: none
+                if sp is not None:
+                    self._seed[s] = np.int32(sp.seed & 0x7FFFFFFF)
+                    self._temp[s] = sp.temperature
+                    self._topk[s] = sp.top_k
+                    self._topp[s] = sp.top_p
+                if req.queue_wait_s is not None:
+                    self.metrics.record_wait("queue_wait", req.queue_wait_s,
+                                             tenant=self.tenant)
         return placed
 
     def _emit(self, req: GenerationRequest, token: int) -> None:
@@ -600,9 +602,10 @@ class ServingEngine:
         return fn
 
     def _sample_first(self, logits_row, slot: int) -> int:
-        return int(np.asarray(self._sample1(
-            logits_row, self._seed[slot], np.int32(0), self._temp[slot],
-            self._topk[slot], self._topp[slot])))
+        with self.metrics.span("serve/sample/readback"):
+            return int(np.asarray(self._sample1(
+                logits_row, self._seed[slot], np.int32(0), self._temp[slot],
+                self._topk[slot], self._topp[slot])))
 
     def _emit_first_tokens(self, group, firsts) -> None:
         for (s, req), first in zip(group, firsts):
@@ -658,24 +661,30 @@ class ServingEngine:
         request's first token samples from its own logits row and its KV
         rows scatter (quantize-on-insert) into its own slot."""
         n = _pow2_ceil(len(group))
-        toks = np.zeros((n, bucket), np.int32)
-        for i, (s, req) in enumerate(group):
-            toks[i, :len(req.prompt)] = req.prompt
-        t0 = self.clock()
-        logits, pstate = self._prefill_fn(bucket, n)(self.params,
-                                                     jnp.asarray(toks))
+        span = self.metrics.span
+        with span("serve/prefill/pack"):
+            toks = np.zeros((n, bucket), np.int32)
+            for i, (s, req) in enumerate(group):
+                toks[i, :len(req.prompt)] = req.prompt
+            t0 = self.clock()
+            toks = jnp.asarray(toks)
+        with span("serve/prefill/dispatch"):
+            logits, pstate = self._prefill_fn(bucket, n)(self.params, toks)
         firsts = []
         total = 0
         fork_leaders: dict = {}
-        for i, (s, req) in enumerate(group):
-            plen = len(req.prompt)
-            total += plen
-            firsts.append(self._sample_first(logits[i, plen - 1], s))
-            if self.paged:
-                self._paged_insert_fp(s, req, pstate, i, fork_leaders)
-            else:
-                self.kv.reset_slot(s)
-                self.kv.insert_prefill(s, pstate, plen, bucket, row=i)
+        # the first tokens' blocking reads (serve/sample/readback inside)
+        # and the slot inserts
+        with span("serve/prefill/readback"):
+            for i, (s, req) in enumerate(group):
+                plen = len(req.prompt)
+                total += plen
+                firsts.append(self._sample_first(logits[i, plen - 1], s))
+                if self.paged:
+                    self._paged_insert_fp(s, req, pstate, i, fork_leaders)
+                else:
+                    self.kv.reset_slot(s)
+                    self.kv.insert_prefill(s, pstate, plen, bucket, row=i)
         self.metrics.record("prefill", self.clock() - t0, total,
                             tenant=self.tenant)
         self.last_step_tokens += total
@@ -693,54 +702,59 @@ class ServingEngine:
         would have copied out)."""
         B = self.pool.block if self.paged else self.prefix_cache.block
         n = _pow2_ceil(len(group))
+        span = self.metrics.span
         t0 = self.clock()
-        # scratch capacity on the BLOCK grid: a bucket capped at a
-        # non-multiple-of-B max_len would make the last chunk's write run
-        # past the buffer, where dynamic_update_slice clamps the start and
-        # silently overwrites real rows with padding. Rounding up keeps
-        # every chunk write in-bounds; the slot insert below copies only the
-        # first min(S, max_len) rows back out.
-        S = -(-bucket // B) * B
-        state = self.plan.decode_state(n, S)
-        if m:
-            if self.paged:
-                rows = self.pool.gather_rows(list(keys))
-            else:
-                rows = {key: jnp.asarray(val)
-                        for key, val in self.prefix_cache.gather(keys).items()}
-            state = {key: (val if key == "len" else
-                           val.at[:, :, :m].set(rows[key][:, None]))
-                     for key, val in state.items()}
-            state["len"] = jnp.asarray(m, jnp.int32)
-        max_plen = max(len(req.prompt) for _, req in group)
-        n_chunks = -(-(max_plen - m) // B)
-        toks = np.zeros((n, n_chunks * B), np.int32)
-        for i, (s, req) in enumerate(group):
-            toks[i, :len(req.prompt) - m] = req.prompt[m:]
-        first_logits = [None] * len(group)
-        fn = self._chunk_fn(S, n)
-        for c in range(n_chunks):
-            logits, state = fn(self.params, state,
-                               jnp.asarray(toks[:, c * B:(c + 1) * B]))
+        with span("serve/prefill/pack"):
+            # scratch capacity on the BLOCK grid: a bucket capped at a
+            # non-multiple-of-B max_len would make the last chunk's write
+            # run past the buffer, where dynamic_update_slice clamps the
+            # start and silently overwrites real rows with padding. Rounding
+            # up keeps every chunk write in-bounds; the slot insert below
+            # copies only the first min(S, max_len) rows back out.
+            S = -(-bucket // B) * B
+            state = self.plan.decode_state(n, S)
+            if m:
+                if self.paged:
+                    rows = self.pool.gather_rows(list(keys))
+                else:
+                    rows = {key: jnp.asarray(val) for key, val
+                            in self.prefix_cache.gather(keys).items()}
+                state = {key: (val if key == "len" else
+                               val.at[:, :, :m].set(rows[key][:, None]))
+                         for key, val in state.items()}
+                state["len"] = jnp.asarray(m, jnp.int32)
+            max_plen = max(len(req.prompt) for _, req in group)
+            n_chunks = -(-(max_plen - m) // B)
+            toks = np.zeros((n, n_chunks * B), np.int32)
             for i, (s, req) in enumerate(group):
-                ci, pi = divmod(len(req.prompt) - 1 - m, B)
-                if ci == c:    # this chunk holds the request's last token
-                    first_logits[i] = logits[i, pi]
+                toks[i, :len(req.prompt) - m] = req.prompt[m:]
+        first_logits = [None] * len(group)
+        with span("serve/prefill/dispatch"):
+            fn = self._chunk_fn(S, n)
+            for c in range(n_chunks):
+                logits, state = fn(self.params, state,
+                                   jnp.asarray(toks[:, c * B:(c + 1) * B]))
+                for i, (s, req) in enumerate(group):
+                    ci, pi = divmod(len(req.prompt) - 1 - m, B)
+                    if ci == c:    # this chunk holds the request's last token
+                        first_logits[i] = logits[i, pi]
         firsts = []
         total = 0
         copy = min(S, self.max_len)     # slot rows past plen stay masked
         fork_leaders: dict = {}
-        for i, (s, req) in enumerate(group):
-            plen = len(req.prompt)
-            total += plen - m
-            firsts.append(self._sample_first(first_logits[i], s))
-            if self.paged:
-                self._paged_insert_state(s, req, state, i, m, fork_leaders)
-                self._paged_publish(req)
-            else:
-                self.kv.reset_slot(s)
-                self.kv.insert_rows(s, state, plen, copy, row=i)
-                self._publish_prefix(req, m, state, i)
+        with span("serve/prefill/readback"):
+            for i, (s, req) in enumerate(group):
+                plen = len(req.prompt)
+                total += plen - m
+                firsts.append(self._sample_first(first_logits[i], s))
+                if self.paged:
+                    self._paged_insert_state(s, req, state, i, m,
+                                             fork_leaders)
+                    self._paged_publish(req)
+                else:
+                    self.kv.reset_slot(s)
+                    self.kv.insert_rows(s, state, plen, copy, row=i)
+                    self._publish_prefix(req, m, state, i)
         self.metrics.record("prefill", self.clock() - t0, total,
                             tenant=self.tenant)
         self.last_step_tokens += total
@@ -921,30 +935,33 @@ class ServingEngine:
         """One batched forward; every request resolves (and frees its slot)
         before this returns — encode requests never outlive their step."""
         n = _pow2_ceil(len(group))
-        toks = np.zeros((n, bucket), np.int32)
-        lens = np.ones(n, np.int32)      # padding rows: length-1, masked
-        total = 0
-        for i, (s, req) in enumerate(group):
-            plen = len(req.tokens)
-            toks[i, :plen] = req.tokens
-            lens[i] = plen
-            total += plen
-        t0 = self.clock()
-        out = self._encode_fn(bucket, n)(self.params, jnp.asarray(toks),
-                                         jnp.asarray(lens))
-        out = {task: np.asarray(v) for task, v in out.items()}
-        self.metrics.record("encode", self.clock() - t0, total,
-                            tenant=self.tenant)
-        self.last_step_encode_tokens += total
-        self.last_step_tokens += total
-        for i, (s, req) in enumerate(group):
-            if self.scheduler.active[s] is not req:
-                continue   # an earlier on_result callback cancelled it
-            req.result = out[req.task][i]
-            self._finalize_slotted(s, req, "done")
-            if req.latency_s is not None:
-                self.metrics.record_wait("encode_latency", req.latency_s,
-                                         tenant=self.tenant)
+        total = sum(len(req.tokens) for _, req in group)
+        span = self.metrics.span
+        with span("serve/encode/group", bucket=bucket, rows=n, useful=total):
+            with span("serve/encode/pack"):
+                toks = np.zeros((n, bucket), np.int32)
+                lens = np.ones(n, np.int32)  # padding rows: length-1, masked
+                for i, (s, req) in enumerate(group):
+                    toks[i, :len(req.tokens)] = req.tokens
+                    lens[i] = len(req.tokens)
+                t0 = self.clock()
+                toks, lens = jnp.asarray(toks), jnp.asarray(lens)
+            with span("serve/encode/dispatch"):
+                out = self._encode_fn(bucket, n)(self.params, toks, lens)
+            with span("serve/encode/readback"):   # the host blocks here
+                out = {task: np.asarray(v) for task, v in out.items()}
+            self.metrics.record("encode", self.clock() - t0, total,
+                                tenant=self.tenant)
+            self.metrics.count("encode_tokens_useful", total)
+            self.metrics.count("encode_tokens_computed", n * bucket)
+            self.last_step_encode_tokens += total
+            self.last_step_tokens += total
+            with span("serve/encode/finalize"):
+                for i, (s, req) in enumerate(group):
+                    if self.scheduler.active[s] is not req:
+                        continue   # an earlier on_result callback cancelled
+                    req.result = out[req.task][i]
+                    self._finalize_slotted(s, req, "done")
 
     def _encoder_step(self) -> None:
         """mode='encoder': the whole step is admit + batched encode — there
@@ -986,26 +1003,29 @@ class ServingEngine:
         for s in active:
             toks[s, 0] = self.generated[s][-1]
         t0 = self.clock()
-        if self.paged:
-            # block-table indirection for the jnp reference path: gather a
-            # dense-shaped view and feed the SAME jitted step the dense
-            # layout compiled — garbage rows from table padding are masked
-            # to exact zeros inside the attention (DESIGN.md §15), so the
-            # streams stay bit-identical. The step writes each slot's new
-            # row into the (donated) view; append_from scatters it back to
-            # the pool block its table maps that position to.
-            state = self.kv.gather_state()
-            next_tok, new_state = self._step(
-                self.params, state, jnp.asarray(toks),
-                self._seed, self._gen_steps(), self._temp, self._topk,
-                self._topp)
-            self.kv.append_from(new_state, active)
-        else:
-            next_tok, self.kv.state = self._step(
-                self.params, self.kv.state, jnp.asarray(toks),
-                self._seed, self._gen_steps(), self._temp, self._topk,
-                self._topp)
-        next_tok = np.asarray(next_tok)
+        with self.metrics.span("serve/decode/dispatch"):
+            if self.paged:
+                # block-table indirection for the jnp reference path: gather
+                # a dense-shaped view and feed the SAME jitted step the dense
+                # layout compiled — garbage rows from table padding are
+                # masked to exact zeros inside the attention (DESIGN.md §15),
+                # so the streams stay bit-identical. The step writes each
+                # slot's new row into the (donated) view; append_from
+                # scatters it back to the pool block its table maps that
+                # position to.
+                state = self.kv.gather_state()
+                next_tok, new_state = self._step(
+                    self.params, state, jnp.asarray(toks),
+                    self._seed, self._gen_steps(), self._temp, self._topk,
+                    self._topp)
+                self.kv.append_from(new_state, active)
+            else:
+                next_tok, self.kv.state = self._step(
+                    self.params, self.kv.state, jnp.asarray(toks),
+                    self._seed, self._gen_steps(), self._temp, self._topk,
+                    self._topp)
+        with self.metrics.span("serve/decode/readback"):
+            next_tok = np.asarray(next_tok)
         self.metrics.record("decode", self.clock() - t0, len(active),
                             tenant=self.tenant)
         self.last_step_tokens += len(active)
@@ -1063,11 +1083,13 @@ class ServingEngine:
             else:                                  # submit() bans empty
                 toks[s, 0] = self.generated[s][-1]  # prompts: always filled
         t0 = self.clock()
-        next_tok, self.state = self._step(
-            self.params, self.state, jnp.asarray(toks),
-            self._seed, self._gen_steps(), self._temp, self._topk,
-            self._topp)
-        next_tok = np.asarray(next_tok)
+        with self.metrics.span("serve/decode/dispatch"):
+            next_tok, self.state = self._step(
+                self.params, self.state, jnp.asarray(toks),
+                self._seed, self._gen_steps(), self._temp, self._topk,
+                self._topp)
+        with self.metrics.span("serve/decode/readback"):
+            next_tok = np.asarray(next_tok)
         self._cursor += 1
         # a slot emits a generated token this step once it has consumed its
         # last prompt token, i.e. pos >= plen - 1 before the increment

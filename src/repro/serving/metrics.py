@@ -3,11 +3,26 @@
 Records (kind, seconds, tokens) step events — kind is 'prefill', 'decode' or
 'encode' (the prefill-only request path, DESIGN.md §14) — plus per-request
 wait samples ('ttft': submit → first emitted token, 'queue_wait': submit →
-slot admission, 'encode_latency': submit → encode result), and summarizes
-tokens/sec, p50/p99 step latency per kind and p50/p99 of the per-request
-waits. Wait samples are kept OUT of the busy-time denominator — queueing is
-not compute, so it must not deflate tokens/sec. Pure host-side bookkeeping;
-never touches device state.
+slot admission), and summarizes tokens/sec, p50/p99 step latency per kind and
+p50/p99 of the per-request waits. Wait samples are kept OUT of the busy-time
+denominator — queueing is not compute, so it must not deflate tokens/sec.
+Pure host-side bookkeeping; never touches device state.
+
+Spans (DESIGN.md §7): ``with metrics.span("serve/encode/readback"):`` opens a
+``jax.profiler.TraceAnnotation`` of that name (its keyword args become the
+event's stats), so while a profiler trace is running the span lands on the
+trace's host plane, on the device trace's clock; with none running the
+annotation is inert. The span is also stamped with the recorder's injected
+clock and rolls up into plain per-name counters — ``n``, total seconds
+``s``, self seconds ``self_s`` (duration less that of the spans opened inside
+it) and the longest single duration ``max_s`` — surfaced under the summary's
+``spans`` key. No sample lists: a span costs a few dictionary updates. The
+engine is single-threaded, so one stack of open spans gives the nesting.
+
+Counters: ``count(name, n)`` adds to a plain integer surfaced under its own
+name in the summary — the engine counts ``encode_tokens_useful`` (the
+requests' tokens) and ``encode_tokens_computed`` (rows × bucket, padded rows
+included) per encode group.
 
 Multi-tenancy: ``record``/``record_wait`` take an optional ``tenant`` label.
 Labeled events additionally roll up into plain-integer per-(tenant, kind)
@@ -47,6 +62,7 @@ from collections import deque
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .clock import Clock
 
@@ -54,7 +70,7 @@ from .clock import Clock
 STEP_KINDS = ("prefill", "decode", "encode")
 
 #: per-request wait kinds recorded via ``record_wait``
-WAIT_KINDS = ("ttft", "queue_wait", "encode_latency")
+WAIT_KINDS = ("ttft", "queue_wait")
 
 #: default bounded-window length (samples kept per stream)
 DEFAULT_WINDOW = 65536
@@ -88,6 +104,9 @@ class ServeMetrics:
         # the steady percentile.
         self._first: dict = {}
         self._lifetime: dict = {}
+        # spans open right now, innermost last; outlives pop_summary() so a
+        # span open across a drain still closes into the new window
+        self._open: list[_Span] = []
         self._reset()
 
     def _reset(self) -> None:
@@ -104,6 +123,9 @@ class ServeMetrics:
         self._label_waits: dict[tuple[str, str], int] = {}
         # KV memory gauges (paged engines): last-write-wins snapshot dict
         self._kv: dict = {}
+        # span name -> [n, s, self_s, max_s]; counter name -> int
+        self._spans: dict[str, list] = {}
+        self._counts: dict[str, int] = {}
 
     def record(self, kind: str, seconds: float, tokens: int,
                tenant: Optional[str] = None) -> None:
@@ -119,12 +141,22 @@ class ServeMetrics:
 
     def record_wait(self, kind: str, seconds: float,
                     tenant: Optional[str] = None) -> None:
-        """Per-request wait sample: 'ttft', 'queue_wait', 'encode_latency'."""
+        """Per-request wait sample: 'ttft' or 'queue_wait'."""
         assert kind in WAIT_KINDS, kind
         self._waits.append((kind, seconds))
         if tenant is not None:
             key = (tenant, kind)
             self._label_waits[key] = self._label_waits.get(key, 0) + 1
+
+    def span(self, name: str, **args: int) -> "_Span":
+        """Context manager timing one host span named ``name`` (``/``-separated
+        layers, e.g. ``serve/encode/dispatch``); ``args`` are cheap ints
+        written as the profiler event's stats."""
+        return _Span(self, name, args)
+
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to the plain counter ``name`` (summary key ``name``)."""
+        self._counts[name] = self._counts.get(name, 0) + n
 
     def update_kv(self, gauges: dict) -> None:
         """Overwrite the KV memory gauges (``BlockPool.stats()``): gauges
@@ -202,12 +234,16 @@ class ServeMetrics:
             out["by_label"] = self._by_label()
         if self._kv:
             out["kv"] = dict(self._kv)
+        out.update(self._counts)
+        if self._spans:
+            out["spans"] = {name: {"n": n, "s": t, "self_s": own, "max_s": mx}
+                            for name, (n, t, own, mx) in self._spans.items()}
         return out
 
     def pop_summary(self) -> dict:
         """Summarize-and-reset: the bounded-memory way to consume metrics
-        from a long-lived engine (windows, per-tenant counters and the wall
-        clock all restart)."""
+        from a long-lived engine (windows, per-tenant, span and plain
+        counters, and the wall clock all restart)."""
         out = self.summary()
         self._reset()
         return out
@@ -241,4 +277,44 @@ class ServeMetrics:
                 f"({kv.get('blocks_in_use', 0)}/{kv.get('blocks_total', 0)} "
                 f"blocks, {kv.get('prefix_blocks', 0)} prefix, "
                 f"{kv.get('cow_forks', 0)} forks)")
+        for name, c in s.get("spans", {}).items():
+            parts.append(f"{name}: {c['n']}x "
+                         f"mean {c['s'] / c['n'] * 1e3:.3f}ms "
+                         f"max {c['max_s'] * 1e3:.3f}ms")
         return " | ".join(parts)
+
+
+class _Span:
+    """One open span of a :class:`ServeMetrics` (see ``ServeMetrics.span``).
+    A plain class rather than a generator context manager: it runs several
+    times per engine step, and this is the cheaper form."""
+
+    __slots__ = ("_m", "_name", "_annot", "_t0", "_child")
+
+    def __init__(self, metrics: ServeMetrics, name: str, args: dict):
+        self._m = metrics
+        self._name = name
+        self._annot = TraceAnnotation(name, **args)
+        self._child = 0.0
+
+    def __enter__(self) -> "_Span":
+        self._annot.__enter__()
+        self._m._open.append(self)
+        self._t0 = self._m._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        m = self._m
+        dur = m._clock() - self._t0
+        m._open.pop()
+        if m._open:
+            m._open[-1]._child += dur
+        cell = m._spans.get(self._name)
+        if cell is None:
+            cell = m._spans[self._name] = [0, 0.0, 0.0, 0.0]
+        cell[0] += 1
+        cell[1] += dur
+        cell[2] += dur - self._child
+        if dur > cell[3]:
+            cell[3] = dur
+        self._annot.__exit__(*exc)
