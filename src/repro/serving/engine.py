@@ -949,9 +949,13 @@ class ServingEngine:
             with span("serve/encode/dispatch"):
                 out = self._encode_fn(bucket, n)(self.params, toks, lens)
             with span("serve/encode/readback"):   # the host blocks here
-                out = {task: np.asarray(v) for task, v in out.items()}
+                # only the heads the group asked for, their copies started
+                # together: each blocking copy is a round trip to the device
+                tasks = {req.task for _, req in group}
+                out = jax.device_get({t: out[t] for t in tasks})
             self.metrics.record("encode", self.clock() - t0, total,
                                 tenant=self.tenant)
+            self.metrics.count("encode_arrays_read", len(out))
             self.metrics.count("encode_tokens_useful", total)
             self.metrics.count("encode_tokens_computed", n * bucket)
             self.last_step_encode_tokens += total

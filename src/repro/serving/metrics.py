@@ -21,8 +21,9 @@ engine is single-threaded, so one stack of open spans gives the nesting.
 
 Counters: ``count(name, n)`` adds to a plain integer surfaced under its own
 name in the summary — the engine counts ``encode_tokens_useful`` (the
-requests' tokens) and ``encode_tokens_computed`` (rows × bucket, padded rows
-included) per encode group.
+requests' tokens), ``encode_tokens_computed`` (rows × bucket, padded rows
+included) and ``encode_arrays_read`` (outputs copied to the host) per encode
+group.
 
 Multi-tenancy: ``record``/``record_wait`` take an optional ``tenant`` label.
 Labeled events additionally roll up into plain-integer per-(tenant, kind)

@@ -85,29 +85,64 @@ def _direct(model, prompts, bucket, task):
                           jnp.asarray(lens))[task])
 
 
+class _CountedCopy:
+    """A device output that counts its host copies under its task's name."""
+
+    def __init__(self, arr, task, copies):
+        self._arr, self._task, self._copies = arr, task, copies
+
+    def __array__(self, dtype=None, copy=None):
+        self._copies[self._task] = self._copies.get(self._task, 0) + 1
+        return np.asarray(self._arr, dtype)
+
+
+def _count_copies(eng):
+    """Wrap ``eng._encode_fn`` so every output's host copy is counted."""
+    copies, real = {}, eng._encode_fn
+
+    def counted_fn(bucket, n):
+        fn = real(bucket, n)
+
+        def call(*a):
+            return {k: _CountedCopy(v, k, copies) for k, v in fn(*a).items()}
+        return call
+    eng._encode_fn = counted_fn
+    return copies
+
+
 @pytest.mark.parametrize("mode", ["int8", "int4"])
-@pytest.mark.parametrize("task", ["classify", "embed", "score"])
-def test_engine_batched_matches_direct_forward(mode, task):
+@pytest.mark.parametrize("tasks", [
+    ("classify",) * 4, ("embed",) * 4, ("score",) * 4,
+    ("classify", "embed", "score", "classify"),
+], ids=["classify", "embed", "score", "mixed"])
+def test_engine_batched_matches_direct_forward(mode, tasks):
     """One mixed-length group through the engine == the direct batched
     forward, byte-for-byte (int8 AND int4 plans) — the engine's grouping,
-    bucketing, row routing and result slicing add nothing numerically."""
+    bucketing, row routing and result slicing add nothing numerically.
+    The group copies each output it asked for to the host once, and never
+    one that no request in it asked for."""
     model = _encoder_model(mode)
     eng = ServingEngine(model, slots=4, max_len=64, clock=VirtualClock())
+    copies = _count_copies(eng)
     # lengths 5..8 share one bucket (8), so all four run as ONE group of 4
     prompts = _prompts(256, (5, 6, 7, 8), seed=mode == "int4")
-    handles = [eng.submit_encode(EncodeRequest(tokens=p, task=task))
-               for p in prompts]
+    handles = [eng.submit_encode(EncodeRequest(tokens=p, task=t))
+               for p, t in zip(prompts, tasks)]
     eng.run_until_drained()
 
-    want = _direct(model, prompts, 8, task)
-    for i, (p, h) in enumerate(zip(prompts, handles)):
+    summary = eng.metrics.summary()
+    assert summary["spans"]["serve/encode/group"]["n"] == 1
+    assert copies == {t: 1 for t in tasks}
+    assert summary["encode_arrays_read"] == len(set(tasks))
+    for i, (p, t, h) in enumerate(zip(prompts, tasks, handles)):
         res = h.result()
         assert res.finish_reason == "done"
-        np.testing.assert_array_equal(np.asarray(res.value), want[i])
+        np.testing.assert_array_equal(np.asarray(res.value),
+                                      _direct(model, prompts, 8, t)[i])
         # and the exact-length unbatched eager forward agrees numerically
         logits, _ = bert_classify_logits(model.params, model.plan,
                                          jnp.asarray(p[None]))
-        if task == "classify":
+        if t == "classify":
             ref = np.asarray(logits)[0]
             np.testing.assert_allclose(np.asarray(res.value), ref,
                                        rtol=2e-5, atol=1e-7)
@@ -233,6 +268,9 @@ def test_decoder_score_is_batch_independent_loglikelihood():
             eng.submit(GenerationRequest(prompt=np.arange(1, 6),
                                          max_new_tokens=3))
         eng.run_until_drained()
+        # the decoder's forward returns 'score' alone: one copy a group
+        s = eng.metrics.summary()
+        assert s["encode_arrays_read"] == s["spans"]["serve/encode/group"]["n"]
         return [np.asarray(h.result().value) for h in hs]
 
     together = run(prompts, with_gen=True)

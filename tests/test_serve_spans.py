@@ -5,8 +5,8 @@ total, self time, longest) stamped on the recorder's injected clock, and
 writes the same span into any active ``jax.profiler`` trace. The engine
 opens spans at its layer boundaries (``serve/step``, ``serve/admit``,
 ``serve/encode/*``, ``serve/prefill/*``, ``serve/decode/*``,
-``serve/sample/readback``) and counts the useful and computed tokens of
-every encode group. On a ``VirtualClock`` every number here is exact: the
+``serve/sample/readback``) and counts the useful and computed tokens and
+the outputs read back of every encode group. On a ``VirtualClock`` every number here is exact: the
 clock moves only where a test moves it.
 """
 import jax
@@ -24,6 +24,7 @@ from repro.serving import (EncodeRequest, GenerationRequest, ServeMetrics,
 KEY = jax.random.PRNGKey(0)
 DISPATCH_S = 0.001       # virtual seconds one jitted encode call takes
 READBACK_S = 0.002       # virtual seconds one output's host copy takes
+N_OUT = 1                # classify-only groups copy the classify head alone
 LENS = (5, 9, 17)        # buckets 8, 16, 32: three groups of one row
 
 
@@ -137,21 +138,20 @@ def stepped():
 def test_encoder_step_nests_spans_once_per_group(stepped):
     sp = stepped["spans"]
     groups = len(LENS)
-    n_out = 3                     # embed, classify and score per forward
     assert sp["serve/step"]["n"] == 1 and sp["serve/admit"]["n"] == 1
     for name in ("group", "pack", "dispatch", "readback", "finalize"):
         assert sp[f"serve/encode/{name}"]["n"] == groups, name
     assert sp["serve/encode/dispatch"]["s"] == pytest.approx(
         groups * DISPATCH_S)
     assert sp["serve/encode/readback"]["s"] == pytest.approx(
-        groups * n_out * READBACK_S)
+        groups * N_OUT * READBACK_S)
     assert sp["serve/encode/readback"]["max_s"] == pytest.approx(
-        n_out * READBACK_S)
+        N_OUT * READBACK_S)
     # all the clock moved lies in the children: the group and the step
     # hold them (zero self time), and the step holds the groups
     group = sp["serve/encode/group"]
     assert group["s"] == pytest.approx(groups * (DISPATCH_S
-                                                 + n_out * READBACK_S))
+                                                 + N_OUT * READBACK_S))
     assert group["self_s"] == pytest.approx(0.0, abs=1e-12)
     assert sp["serve/step"]["s"] == pytest.approx(group["s"])
     assert sp["serve/step"]["self_s"] == pytest.approx(0.0, abs=1e-12)
@@ -160,6 +160,7 @@ def test_encoder_step_nests_spans_once_per_group(stepped):
 def test_encode_token_counters_match_a_hand_count(stepped):
     assert stepped["encode_tokens_useful"] == sum(LENS) == 31
     assert stepped["encode_tokens_computed"] == 8 + 16 + 32 == 56
+    assert stepped["encode_arrays_read"] == len(LENS) * N_OUT == 3
 
 
 def test_encode_step_sample_unchanged(stepped):
@@ -168,7 +169,7 @@ def test_encode_step_sample_unchanged(stepped):
     assert stepped["encode_steps"] == len(LENS)
     assert stepped["encode_tokens"] == sum(LENS)
     assert stepped["encode_mean_ms"] == pytest.approx(
-        (DISPATCH_S + 3 * READBACK_S) * 1e3)
+        (DISPATCH_S + N_OUT * READBACK_S) * 1e3)
 
 
 def test_encode_latency_wait_stream_removed(stepped):
